@@ -218,9 +218,6 @@ def solver_backend_benchmarks(quick: bool = False):
     print("== solver-backend benchmarks (numpy vs jit+vmap jax) ==")
     print("name,us_per_call,derived")
     out = {}
-    if not resource_opt_jax.available():           # pragma: no cover
-        print("solver_backend,skipped,jax-unavailable")
-        return out
 
     plan = resnet18_plan(img=224, n_classes=1000)
     cuts = plan.enumerate_cuts()
@@ -280,7 +277,6 @@ def sweep_benchmarks(quick: bool = False):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro.core import resource_opt_jax
     from repro.core.mission import sweep_revolutions
     from repro.core.sl_step import autoencoder_adapter, make_sl_pass
     from repro.core.splitting import resnet18_plan
@@ -291,9 +287,6 @@ def sweep_benchmarks(quick: bool = False):
     print("== revolution-sweep benchmarks (on-device planning) ==")
     print("name,us_per_call,derived")
     out = {}
-    if not resource_opt_jax.available():           # pragma: no cover
-        print("sweep_revolutions,skipped,jax-unavailable")
-        return out
 
     cuts = resnet18_plan(img=224, n_classes=1000).enumerate_cuts()
     ring_sizes = [25, 100] if quick else [25, 100, 1000]
@@ -978,7 +971,9 @@ def main(argv=None) -> None:
                          "sweep, paper tables skipped, no BENCH_<rev> "
                          "emission (results/bench_quick.json only)")
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     t0 = time.time()
     # a fresh global metrics registry: every engine any section builds
     # parents to it, and its aggregate snapshot becomes the BENCH

@@ -214,7 +214,12 @@ def allocation_from_times(budget: PassBudget, costs: SplitCosts,
     up_bits = n * costs.dtx_bits
 
     def _f(dev: DeviceComputeSpec, w: float, t: float) -> float:
-        return dev.freq_for_time(w, t, n) if w > 0 else 0.0
+        if w <= 0:
+            return 0.0
+        # t = 0 for positive work is a t_min that underflowed (a vanishing
+        # workload such as 1e-308 FLOPs): the phase runs at f_max, not at
+        # an infinite frequency whose raw energy (eq. 7) would be inf
+        return dev.f_max_hz if t <= 0 else dev.freq_for_time(w, t, n)
 
     def _p(bits: float, t: float) -> float:
         return budget.link.power_for_time(bits, t, d) if bits > 0 else 0.0

@@ -349,11 +349,6 @@ def sweep_revolutions(ring_sizes: Sequence[int],
     """
     from repro.core import resource_opt_jax as roj
 
-    if not roj.available():                       # pragma: no cover
-        raise RuntimeError(
-            "sweep_revolutions needs the JAX solver backend "
-            "(repro.core.resource_opt_jax); install jax or use "
-            "RevolutionPlanner with backend='numpy' instead")
     import jax.numpy as jnp
 
     budget = PassBudget() if budget is None else budget

@@ -452,12 +452,7 @@ def _resolve_backend(backend: Optional[str], n_instances: int) -> str:
     """Map the user's backend choice (or "auto") to "numpy" | "jax"."""
     backend = backend or os.environ.get("REPRO_SOLVER_BACKEND", "auto")
     if backend == "auto":
-        try:
-            from repro.core import resource_opt_jax
-        except Exception:                        # pragma: no cover
-            return "numpy"
-        if not resource_opt_jax.available():
-            return "numpy"
+        from repro.core import resource_opt_jax
         if resource_opt_jax.on_accelerator() \
                 or n_instances >= _AUTO_MIN_JAX_BATCH:
             return "jax"

@@ -22,14 +22,23 @@ Numerical notes
 The dual λ spans hundreds of decades (λ_hi/λ_lo brackets are analytic,
 from the marginals at t_min and at the whole budget), so the solver runs
 in **float64** regardless of the process-wide JAX default: every entry
-point traces and executes under ``jax.experimental.enable_x64``, which
-scopes double precision to this module without flipping the global flag
+point traces and executes under ``jax.enable_x64(True)``, which scopes
+double precision to this module without flipping the global flag
 (the SL training stack stays float32).  The Lambert-W branch point gets
 the same series guard as the NumPy path: for λ·g̃ below ~1e-6 the
 argument (λ·g̃ − 1)/e rounds into the branch point where W₀ loses all
 precision, and the series x ≈ √(2·λ·g̃) of ``e^x (x−1) + 1 = λ·g̃`` is
 exact; two Newton polish steps on the cancellation-free residual restore
 full double precision everywhere else.
+
+On a TPU, float64 is emulated with pairs of float32 values: it gains
+precision but keeps float32's exponent range (about 1e±38), and the
+compiler rounds any constant beyond it to 0 or inf.  So every guard
+constant here (``_TINY``, ``_HUGE``, the ``_X_MAX`` exponent cap) lies
+inside that range, the bisection midpoint is √λ_lo·√λ_hi (the product
+λ_lo·λ_hi can leave the range), and the processing constants travel as
+cube roots so ``k = (k_cbrt·n·w)³`` never forms the out-of-range
+``(n·w)³``.  The NumPy oracle keeps its float64-range guards.
 
 The phase structure is static (the canonical [sat_proc, downlink,
 gs_proc, uplink] layout with liveness masks), so one compiled executable
@@ -43,38 +52,31 @@ import functools
 import math
 from typing import NamedTuple, Optional, Sequence, Tuple, Union
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 # batch padding shares the repo-wide bucketing schedule with the pass
 # engine's step bucketing: O(log B) compilations, <=25% inert pad rows
 from repro.utils.bucketing import bucket_size as _bucket_batch
 
-try:                                            # gate: CPU-only envs without
-    import jax                                  # jax still import resource_opt
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64 as _enable_x64
-    _JAX_OK = True
-except Exception:                               # pragma: no cover
-    jax = None
-    _JAX_OK = False
-
 _EPS = 1e-12
 _LN2 = math.log(2.0)
-
-
-def available() -> bool:
-    """True when the JAX backend can run in this process."""
-    return _JAX_OK
+# guards inside float32's exponent range, which the TPU's emulated
+# float64 keeps (module docstring); exp(_X_MAX) is still finite there
+_TINY = 1e-30
+_HUGE = 1e30
+_X_MAX = 80.0
 
 
 def on_accelerator() -> bool:
     """True when the default JAX backend is not the host CPU."""
-    return _JAX_OK and jax.default_backend() != "cpu"
+    return jax.default_backend() != "cpu"
 
 
 # --------------------------------------------------------------------------
-# Elementwise building blocks (float64 under enable_x64).
+# Elementwise building blocks (float64 under x64_scope).
 # --------------------------------------------------------------------------
 
 def _lambert_w0(z):
@@ -92,7 +94,7 @@ def _lambert_w0(z):
 
     def halley(_, w):
         w = jnp.maximum(w, -1.0 + 1e-12)        # keep 2w+2 away from zero
-        ew = jnp.exp(jnp.minimum(w, 700.0))
+        ew = jnp.exp(jnp.minimum(w, _X_MAX))
         f = w * ew - z
         denom = ew * (w + 1.0) - (w + 2.0) * f / (2.0 * w + 2.0)
         return w - f / jnp.where(denom != 0.0, denom, 1.0)
@@ -106,11 +108,11 @@ def _lambert_w0(z):
 
 def _comm_neg_deriv_vec(c, gain, t):
     """−E'(t) of a comm phase, cancellation-free (see resource_opt)."""
-    x = jnp.where(t > 0.0, c * _LN2 / jnp.maximum(t, 1e-300), jnp.inf)
-    xs = jnp.minimum(x, 500.0)
+    x = jnp.where(t > 0.0, c * _LN2 / jnp.maximum(t, _TINY), jnp.inf)
+    xs = jnp.minimum(x, _X_MAX)
     e = jnp.expm1(xs)
     nd = (e * xs - (e - xs)) / gain
-    return jnp.where(x > 500.0, jnp.inf, nd)
+    return jnp.where(x > _X_MAX, jnp.inf, nd)
 
 
 def _comm_t_of_lambda_vec(c, gain, lam, t_min, t_hi):
@@ -125,20 +127,20 @@ def _comm_t_of_lambda_vec(c, gain, lam, t_min, t_hi):
     x = 1.0 + _lambert_w0(z)
     small = lg < 1e-6
     x = jnp.where(small, jnp.sqrt(2.0 * jnp.maximum(lg, 0.0)), x)
-    x = jnp.maximum(x, 1e-300)
+    x = jnp.maximum(x, _TINY)
     for _ in range(2):
-        xs = jnp.minimum(x, 500.0)
+        xs = jnp.minimum(x, _X_MAX)
         em = jnp.expm1(xs)
         f = em * xs - (em - xs) - lg
         fp = (em + 1.0) * xs
-        x = jnp.maximum(x - f / jnp.maximum(fp, 1e-300), 1e-300)
+        x = jnp.maximum(x - f / jnp.maximum(fp, _TINY), _TINY)
     t = c * _LN2 / x
     return jnp.clip(t, t_min, t_hi)
 
 
 def _proc_t_of_lambda_vec(k, lam, t_min, t_hi):
     """Closed-form t(λ) = (2k/λ)^{1/3} for the processing phases."""
-    t = jnp.cbrt(2.0 * k / jnp.maximum(lam, 1e-300))
+    t = jnp.cbrt(2.0 * k / jnp.maximum(lam, _TINY))
     return jnp.clip(t, t_min, t_hi)
 
 
@@ -214,18 +216,18 @@ def _solve_one(k, tmin_p, cc, tmin_c, gain, t_budget, *, tol, max_iters):
     t_hi = jnp.maximum(t_budget, 0.0)
 
     # ---- analytic λ bracket: total_time(λ) is decreasing in λ ----------
-    nd_p_lo = 2.0 * k / jnp.maximum(tmin_p, 1e-300) ** 3
-    nd_p_hi = 2.0 * k / jnp.maximum(t_hi, 1e-300) ** 3
-    nd_c_lo = _comm_neg_deriv_vec(cc, gain, jnp.maximum(tmin_c, 1e-300))
-    nd_c_hi = _comm_neg_deriv_vec(cc, gain, jnp.maximum(t_hi, 1e-300))
+    nd_p_lo = 2.0 * k / jnp.maximum(tmin_p, _TINY) ** 3
+    nd_p_hi = 2.0 * k / jnp.maximum(t_hi, _TINY) ** 3
+    nd_c_lo = _comm_neg_deriv_vec(cc, gain, jnp.maximum(tmin_c, _TINY))
+    nd_c_hi = _comm_neg_deriv_vec(cc, gain, jnp.maximum(t_hi, _TINY))
     nd_lo = jnp.concatenate([jnp.where(live_p, nd_p_lo, -jnp.inf),
                              jnp.where(live_c, nd_c_lo, -jnp.inf)])
     nd_hi = jnp.concatenate([jnp.where(live_p, nd_p_hi, jnp.inf),
                              jnp.where(live_c, nd_c_hi, jnp.inf)])
     lam_hi0 = jnp.maximum(jnp.nan_to_num(nd_lo.max(), neginf=1.0,
-                                         posinf=1e300), 1e-300)
+                                         posinf=_HUGE), _TINY)
     lam_lo0 = jnp.clip(jnp.nan_to_num(nd_hi.min(), posinf=1.0),
-                       1e-300, lam_hi0)
+                       _TINY, lam_hi0)
 
     def times_at(lam):
         tp = jnp.where(live_p,
@@ -242,7 +244,9 @@ def _solve_one(k, tmin_p, cc, tmin_c, gain, t_budget, *, tol, max_iters):
 
     def body(carry):
         it, lam_lo, lam_hi = carry
-        lam = jnp.sqrt(lam_lo * lam_hi)        # geometric mid: λ spans decades
+        # geometric mid (λ spans decades); the product could leave the
+        # float32 exponent range a TPU's float64 keeps
+        lam = jnp.sqrt(lam_lo) * jnp.sqrt(lam_hi)
         tp, tc = times_at(lam)
         over = (tp.sum() + tc.sum()) > t_budget
         return (it + 1, jnp.where(over, lam, lam_lo),
@@ -250,7 +254,7 @@ def _solve_one(k, tmin_p, cc, tmin_c, gain, t_budget, *, tol, max_iters):
 
     _, lam_lo, lam_hi = lax.while_loop(
         cond, body, (jnp.zeros((), jnp.int32), lam_lo0, lam_hi0))
-    lam = jnp.sqrt(lam_lo * lam_hi)
+    lam = jnp.sqrt(lam_lo) * jnp.sqrt(lam_hi)
     tp, tc = times_at(lam)
 
     # ---- slack redistribution (t_min-clamped phases leave headroom) ----
@@ -271,15 +275,15 @@ def _solve_one(k, tmin_p, cc, tmin_c, gain, t_budget, *, tol, max_iters):
 
     # ---- energies at the final times -----------------------------------
     e_p = jnp.where(live_p & (tp > 0.0),
-                    k / jnp.maximum(tp, 1e-300) ** 2, 0.0)
-    xc = cc * _LN2 / jnp.maximum(tc, 1e-300)
+                    k / jnp.maximum(tp, _TINY) ** 2, 0.0)
+    xc = cc * _LN2 / jnp.maximum(tc, _TINY)
     e_c = jnp.where(live_c & (tc > 0.0),
-                    tc * jnp.expm1(jnp.minimum(xc, 700.0)) / gain, 0.0)
-    e_c = jnp.where(live_c & (xc > 700.0), jnp.inf, e_c)
+                    tc * jnp.expm1(jnp.minimum(xc, _X_MAX)) / gain, 0.0)
+    e_c = jnp.where(live_c & (xc > _X_MAX), jnp.inf, e_c)
 
     # ---- KKT residual: spread of marginals among interior phases -------
-    nd_p = 2.0 * k / jnp.maximum(tp, 1e-300) ** 3
-    nd_c = _comm_neg_deriv_vec(cc, gain, jnp.maximum(tc, 1e-300))
+    nd_p = 2.0 * k / jnp.maximum(tp, _TINY) ** 3
+    nd_c = _comm_neg_deriv_vec(cc, gain, jnp.maximum(tc, _TINY))
     io_p = live_p & (tp > tmin_p * (1.0 + 1e-6)) & (tp < t_hi * (1.0 - 1e-6))
     io_c = live_c & (tc > tmin_c * (1.0 + 1e-6)) & (tc < t_hi * (1.0 - 1e-6))
     marg = jnp.concatenate([jnp.where(io_p, nd_p, jnp.nan),
@@ -311,7 +315,7 @@ def solve_coeffs(coeffs: CoeffArrays, tol: float = 1e-10,
     ``coeffs`` may carry any leading batch shape; the call is traceable,
     so it composes inside larger jitted programs (the revolution sweep
     jits grid construction + shedding + this solve as one executable).
-    NOTE: run under ``enable_x64`` (see :func:`x64_scope`) — the dual
+    NOTE: run under :func:`x64_scope` — the dual
     bisection needs float64 range.
     """
     lead = coeffs.gain.shape
@@ -343,7 +347,7 @@ def shed_fractions(coeffs: CoeffArrays,
     feas_full = no_phase | ((coeffs.t_budget > 0.0)
                             & (tmin_sum <= coeffs.t_budget))
     # one-ulp shave keeps the scaled Σ t_min on the feasible side
-    fit = (coeffs.t_budget / jnp.maximum(tmin_sum, 1e-300)) * (1.0 - 1e-12)
+    fit = (coeffs.t_budget / jnp.maximum(tmin_sum, _TINY)) * (1.0 - 1e-12)
     frac = jnp.where(feas_full, 1.0,
                      jnp.clip(fit, min_fraction, 1.0))
     return jnp.where(no_phase | (coeffs.t_budget > 0.0), frac, min_fraction)
@@ -359,7 +363,7 @@ def shed_and_solve_coeffs(coeffs: CoeffArrays, min_fraction: float = 0.05,
 
 def x64_scope():
     """The float64 scope every entry point of this module runs under."""
-    return _enable_x64()
+    return jax.enable_x64(True)
 
 
 # --------------------------------------------------------------------------
@@ -407,8 +411,6 @@ def solve_batch_jax(budgets, costs, tol: float = 1e-10,
     the revolution planner — runs on device by flipping ``backend``.
     For a zero-copy device pipeline use :func:`solve_coeffs` directly.
     """
-    if not _JAX_OK:                              # pragma: no cover
-        raise RuntimeError("jax backend requested but jax is unavailable")
     from repro.core import resource_opt
 
     blist, clist = resource_opt._broadcast_instances(budgets, costs)
@@ -444,9 +446,9 @@ class GridScalars(NamedTuple):
     isl_rate_bps: "jnp.ndarray"
     isl_tx_power_w: "jnp.ndarray"
     orbit_radius_m: "jnp.ndarray"       # R_earth + altitude
-    sat_k_const: "jnp.ndarray"          # P_p / (f_max³ · (N_c·N_F)³)
+    sat_k_cbrt: "jnp.ndarray"           # P_p^(1/3) / (f_max · N_c·N_F)
     sat_t_const: "jnp.ndarray"          # 1 / (N_c·N_F·f_max)
-    gs_k_const: "jnp.ndarray"
+    gs_k_cbrt: "jnp.ndarray"
     gs_t_const: "jnp.ndarray"
 
 
@@ -460,7 +462,7 @@ def grid_scalars(plane, link, isl, sat_device, gs_device) -> GridScalars:
 
         def dev_consts(dev):
             nc = dev.n_cores * dev.flops_per_cycle
-            return (f64(dev.power_max_w / (dev.f_max_hz ** 3 * nc ** 3)),
+            return (f64(dev.power_max_w ** (1.0 / 3.0) / (dev.f_max_hz * nc)),
                     f64(1.0 / (nc * dev.f_max_hz)))
 
         sat_k, sat_t = dev_consts(sat_device)
@@ -474,8 +476,8 @@ def grid_scalars(plane, link, isl, sat_device, gs_device) -> GridScalars:
             isl_rate_bps=f64(isl.rate_bps),
             isl_tx_power_w=f64(isl.tx_power_w),
             orbit_radius_m=f64(R_EARTH_M + plane.altitude_m),
-            sat_k_const=sat_k, sat_t_const=sat_t,
-            gs_k_const=gs_k, gs_t_const=gs_t)
+            sat_k_cbrt=sat_k, sat_t_const=sat_t,
+            gs_k_cbrt=gs_k, gs_t_const=gs_t)
 
 
 def ring_grid_coeffs(sc: GridScalars, ring_sizes, w1, w2, dtx, disl,
@@ -503,8 +505,8 @@ def ring_grid_coeffs(sc: GridScalars, ring_sizes, w1, w2, dtx, disl,
     t_budget = sc.pass_duration_s - t_fixed
     e_isl = sc.isl_tx_power_w * disl / sc.isl_rate_bps
 
-    k_sat = sc.sat_k_const * (n * w1) ** 3
-    k_gs = sc.gs_k_const * (n * w2) ** 3
+    k_sat = (sc.sat_k_cbrt * n * w1) ** 3
+    k_gs = (sc.gs_k_cbrt * n * w2) ** 3
     tmin_sat = sc.sat_t_const * n * w1
     tmin_gs = sc.gs_t_const * n * w2
     bits = n * dtx
@@ -560,8 +562,8 @@ def ring_pass_coeffs(sc: GridScalars, n_sats, w1, w2, dtx, disl,
     t_budget = sc.pass_duration_s - t_fixed
     e_isl = sc.isl_tx_power_w * disl / sc.isl_rate_bps
 
-    k_sat = sc.sat_k_const * (n * w1) ** 3
-    k_gs = sc.gs_k_const * (n * w2) ** 3
+    k_sat = (sc.sat_k_cbrt * n * w1) ** 3
+    k_gs = (sc.gs_k_cbrt * n * w2) ** 3
     tmin_sat = sc.sat_t_const * n * w1
     tmin_gs = sc.gs_t_const * n * w2
     bits = n * dtx

@@ -33,6 +33,10 @@ if args:
                          "[--scenario baseline|degraded]")
     scenario = args[1]
 
+from repro.utils.compile_cache import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+
 kw = dict(
     n_sats=int(os.environ.get("REPRO_FLEET_SMOKE_SATS", "8")),
     n_planes=int(os.environ.get("REPRO_FLEET_SMOKE_PLANES", "2")),

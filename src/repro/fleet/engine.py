@@ -348,7 +348,12 @@ class FleetEngine:
         self._failed = put(jnp.asarray(failed))
         self._fail_mask = put(jnp.asarray(schedule.fail_mask))
         self._batch_idx = put(jnp.zeros((P,), jnp.int32))
-        self._pass_idx = jnp.zeros((), jnp.int32)
+        # the pass index is replicated on the mesh, as the program
+        # returns it, so every dispatch after the first hits the trace
+        self._pass_idx = jax.device_put(
+            jnp.zeros((), jnp.int32),
+            jax.sharding.NamedSharding(self.mesh,
+                                       jax.sharding.PartitionSpec()))
         # epidemic recovery counters ride the carry; the precomputed
         # spread draws and the static Byzantine mask ship as sharded
         # inputs so the scan reads its own plane's rows

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -36,7 +34,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     k_start = ki * block_k
-    valid_len = len_ref[0, 0]
+    valid_len = len_ref[pl.program_id(0), 0]
 
     @pl.when(k_start < valid_len)
     def _compute():
@@ -72,7 +70,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention(q, k, v, lengths, *, block_k: int = 512,
-                     interpret: bool = True):
+                     interpret: bool):
     """q: (B, H, 1, D); k, v: (B, KV, S, D); lengths: (B,) valid cache len."""
     B, H, _, D = q.shape
     KV, S = k.shape[1], k.shape[2]
@@ -89,8 +87,9 @@ def decode_attention(q, k, v, lengths, *, block_k: int = 512,
         kernel,
         grid=(B, H, n_kv),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ki: (b, 0),
-                         memory_space=pltpu.SMEM),
+            # the whole (B, 1) length table sits in SMEM: a (1, 1) block
+            # of it breaks the TPU's (8, 128) block-tiling rule
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, 1, D), lambda b, h, ki: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, ki, g=group: (b, h // g, ki, 0)),
@@ -104,7 +103,7 @@ def decode_attention(q, k, v, lengths, *, block_k: int = 512,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths2d, q, k, v)

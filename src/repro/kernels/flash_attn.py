@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -106,7 +104,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         window: Optional[int] = None,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True):
+                        interpret: bool):
     """q: (B, H, Sq, D); k, v: (B, KV, Skv, D). Returns (B, H, Sq, D)."""
     B, H, Sq, D = q.shape
     KV, Skv = k.shape[1], k.shape[2]
@@ -141,7 +139,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 
 def _ssd_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, y_ref, hout_ref,
                 h_ref, *, chunk: int, n_chunks: int, seq_len: int):
@@ -85,7 +83,7 @@ def _ssd_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, y_ref, hout_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mamba_chunk_scan(x, dt, a_log, b, c, *, chunk: int = 128,
-                     interpret: bool = True):
+                     interpret: bool):
     """x: (B,S,H,P); dt: (B,S,H); a_log: (H,); b,c: (B,S,N).
 
     Returns (y: (B,S,H,P), h_final: (B,H,P,N)).
@@ -118,7 +116,7 @@ def mamba_chunk_scan(x, dt, a_log, b, c, *, chunk: int = 128,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, alog2d, b, c)

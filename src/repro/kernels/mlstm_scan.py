@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -114,7 +112,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm_chunk_scan(q, k, v, i_pre, f_pre, *, chunk: int = 128,
-                     interpret: bool = True):
+                     interpret: bool):
     """q,k,v: (B,S,H,P); i_pre,f_pre: (B,S,H).
 
     Returns (h: (B,S,H,P), (C: (B,H,P,P), n: (B,H,P,1), m: (B,H))).
@@ -153,7 +151,7 @@ def mlstm_chunk_scan(q, k, v, i_pre, f_pre, *, chunk: int = 128,
             pltpu.VMEM((P, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, i_pre, f_pre)

@@ -27,7 +27,7 @@ def _quant_kernel(x_ref, q_ref, s_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def quantize_rows(x, *, block_rows: int = 256, interpret: bool = True):
+def quantize_rows(x, *, interpret: bool, block_rows: int = 256):
     """x: (rows, d) -> (q int8 (rows, d), scale fp32 (rows, 1))."""
     rows, d = x.shape
     block_rows = min(block_rows, rows)

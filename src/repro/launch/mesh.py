@@ -12,19 +12,29 @@ state (the dry-run must set XLA_FLAGS before the first jax call).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: sharding follows the explicit
+    ``with_sharding_constraint`` / ``device_put`` placements the code
+    makes (``models/param.constrain``, the fleet's ``plane_sharding``),
+    not sharding-in-types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Whatever this host actually has (tests / examples): (n//m, m)."""
     n = len(jax.devices())
     model = max(1, min(model, n))
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _make_mesh((n // model, model), ("data", "model"))
 
 
 def make_fleet_mesh(planes: int):
@@ -41,7 +51,7 @@ def make_fleet_mesh(planes: int):
     n = len(jax.devices())
     planes = max(1, int(planes))
     size = max(d for d in range(1, min(planes, n) + 1) if planes % d == 0)
-    return jax.make_mesh((size,), ("plane",))
+    return _make_mesh((size,), ("plane",))
 
 
 def plane_sharding(mesh, axis: str = "plane"):

@@ -16,6 +16,7 @@ import numpy as np
 from repro import configs
 from repro.models import lm
 from repro.serve.engine import DecodeEngine, Request
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -37,6 +38,7 @@ def main(argv=None):
                     help="serve the SPLIT model cut at this unit boundary "
                     "(satellite half + boundary downlink + ground half)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch)
     params = lm.init(cfg, jax.random.key(args.seed))

@@ -27,6 +27,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.models.param import ShardingRules
 from repro.train.optimizer import AdamWConfig
 from repro.train.step import TrainConfig, TrainState, make_train_step
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -46,6 +47,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     mesh = make_host_mesh(model=args.model_parallel)

@@ -167,9 +167,6 @@ def plan_ring_passes(budget: PassBudget, costs: SplitCosts, *,
     """
     from repro.core import resource_opt_jax as roj
 
-    if not roj.available():                        # pragma: no cover
-        raise RuntimeError("the device constellation engine needs the JAX "
-                           "solver backend (repro.core.resource_opt_jax)")
     n_sats = budget.plane.n_sats if n_sats is None else n_sats
     dtx = costs.dtx_bits if dtx_bits is None else dtx_bits
     items = budget.n_items if n_items is None else n_items

@@ -360,6 +360,7 @@ def test_fleet_on_two_cpu_devices_subprocess():
                XLA_FLAGS="--xla_force_host_platform_device_count=2",
                REPRO_FLEET_SMOKE_SATS="4", REPRO_FLEET_SMOKE_PLANES="2",
                REPRO_FLEET_SMOKE_REVS="2",
+               JAX_ENABLE_COMPILATION_CACHE="false",
                PYTHONPATH="src" + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     proc = subprocess.run(

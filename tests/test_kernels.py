@@ -34,7 +34,7 @@ def test_flash_attn_pallas_vs_ref(B, H, KV, Sq, Skv, D, causal, window):
     q, k, v = rnd(B, H, Sq, D), rnd(B, KV, Skv, D), rnd(B, KV, Skv, D)
     r = ref.attention(q, k, v, causal=causal, window=window)
     p = flash_attention_fwd(q, k, v, causal=causal, window=window,
-                            block_q=64, block_k=64)
+                            block_q=64, block_k=64, interpret=True)
     np.testing.assert_allclose(p, r, atol=2e-5, rtol=2e-5)
 
 
@@ -53,7 +53,8 @@ def test_flash_attn_dtypes(dtype):
     k = rnd(1, 2, 64, 32, dtype=dtype)
     v = rnd(1, 2, 64, 32, dtype=dtype)
     r = ref.attention(q, k, v, causal=True)
-    p = flash_attention_fwd(q, k, v, causal=True, block_q=32, block_k=32)
+    p = flash_attention_fwd(q, k, v, causal=True, block_q=32, block_k=32,
+                            interpret=True)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(p.astype(jnp.float32),
                                r.astype(jnp.float32), atol=tol, rtol=tol)
@@ -108,7 +109,7 @@ def test_decode_attn(B, H, KV, S, D, lens):
     k, v = rnd(B, KV, S, D), rnd(B, KV, S, D)
     lengths = jnp.asarray(lens, jnp.int32)
     r = ref.attention(q, k, v, causal=False, kv_len=lengths)
-    p = pallas_decode(q, k, v, lengths, block_k=64)
+    p = pallas_decode(q, k, v, lengths, block_k=64, interpret=True)
     c = ops.decode_attention(q, k, v, lengths, use_pallas=False)
     np.testing.assert_allclose(p, r, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(c, r, atol=2e-5, rtol=2e-5)
@@ -125,7 +126,8 @@ def test_mamba_scan(B, S, H, P, N, chunk):
     alog = rnd(H) * 0.5
     b, c = rnd(B, S, N), rnd(B, S, N)
     yr, hr = ref.mamba_ssd(x, dt, alog, b, c)
-    yp, hp = mamba_chunk_scan(x, dt, alog, b, c, chunk=chunk)
+    yp, hp = mamba_chunk_scan(x, dt, alog, b, c, chunk=chunk,
+                              interpret=True)
     yj, hj = ops.mamba_scan(x, dt, alog, b, c, chunk=chunk, use_pallas=False)
     np.testing.assert_allclose(yp, yr, atol=5e-4, rtol=5e-4)
     np.testing.assert_allclose(hp, hr, atol=5e-4, rtol=5e-4)
@@ -157,7 +159,8 @@ def test_mlstm_scan(B, S, H, P, chunk):
     q, k, v = rnd(B, S, H, P), rnd(B, S, H, P), rnd(B, S, H, P)
     ip, fp = rnd(B, S, H), rnd(B, S, H) + 1.0
     hr, (Cr, nr, mr) = ref.mlstm(q, k, v, ip, fp)
-    hp, (Cp, np_, mp) = mlstm_chunk_scan(q, k, v, ip, fp, chunk=chunk)
+    hp, (Cp, np_, mp) = mlstm_chunk_scan(q, k, v, ip, fp, chunk=chunk,
+                                         interpret=True)
     hj, (Cj, nj, mj) = ops.mlstm_scan(q, k, v, ip, fp, chunk=chunk,
                                       use_pallas=False)
     np.testing.assert_allclose(hp, hr, atol=1e-4, rtol=1e-4)
@@ -185,7 +188,7 @@ def test_mlstm_decode_step_matches_scan():
                                           (5, 128, 256)])
 def test_split_quant(rows, d, block):
     x = rnd(rows, d) * 7.3
-    qq, ss = pallas_quant(x, block_rows=block)
+    qq, ss = pallas_quant(x, block_rows=block, interpret=True)
     qr, sr = ref.quantize_rows(x)
     np.testing.assert_array_equal(np.asarray(qq), np.asarray(qr))
     np.testing.assert_allclose(ss, sr, rtol=1e-6)

@@ -13,11 +13,8 @@ import pytest
 from repro.core import resource_opt as ro
 from repro.core.energy import PassBudget, SplitCosts, direct_download_costs
 from repro.core.mission import RevolutionPlanner, sweep_revolutions
+from repro.core import resource_opt_jax as roj
 from repro.core.orbits import OrbitalPlane
-
-roj = pytest.importorskip("repro.core.resource_opt_jax")
-if not roj.available():                       # pragma: no cover
-    pytest.skip("jax solver backend unavailable", allow_module_level=True)
 
 BUDGET = PassBudget()
 W_MAX = BUDGET.sat_device.peak_flops * BUDGET.plane.pass_duration_s \
