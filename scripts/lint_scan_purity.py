@@ -7,11 +7,14 @@ The ≤-1-host-sync-per-revolution contract dies quietly: one
 a host-computed constant into the trace (or crashes on tracers weeks
 later).  This lint walks the AST of each engine's device-program
 builder (the ``_compiled`` methods, plus :func:`repro.obs.ring.record`
-which runs inside them) and fails on the three footguns:
+which runs inside them) and fails on the four footguns:
 
 * ``jax.debug.print`` / ``jax.debug.callback`` / ``jax.debug.breakpoint``
 * any ``.block_until_ready`` attribute access
 * any use of ``np.`` / ``numpy.`` (host NumPy inside a traced scope)
+* any use of ``jax.profiler.`` (a host span or annotation there fires
+  once, while the function is traced, and times nothing on the device;
+  name device work with ``jax.named_scope``)
 
 Wired into ``scripts/check.sh``.  Exit 0 = clean, 1 = violations
 (printed as ``path:line: message``), 2 = a guarded scope disappeared —
@@ -74,6 +77,10 @@ def check_scope(fn: ast.AST, path: str) -> List[Tuple[str, int, str]]:
                 hits.append((path, node.lineno,
                              f"host numpy ({dotted}) inside a traced "
                              f"scope — use jnp, or hoist to __init__"))
+            elif dotted.startswith(("jax.profiler.", "profiler.")):
+                hits.append((path, node.lineno,
+                             f"{dotted} inside a traced scope fires once "
+                             f"at trace time — use jax.named_scope"))
     return hits
 
 

@@ -202,6 +202,7 @@ class ArraySolveReport(NamedTuple):
         return self.phase_times.sum(axis=-1) + self.t_fixed
 
 
+@jax.named_scope("planner.solve")
 def _solve_one(k, tmin_p, cc, tmin_c, gain, t_budget, *, tol, max_iters):
     """Solve one problem-(13) instance; shapes (2,)/(); pure JAX."""
     live_p = k > 0.0
@@ -331,6 +332,7 @@ def solve_coeffs(coeffs: CoeffArrays, tol: float = 1e-10,
         e_isl=coeffs.e_isl, t_fixed=coeffs.t_fixed)
 
 
+@jax.named_scope("planner.shed")
 def shed_fractions(coeffs: CoeffArrays,
                    min_fraction: float = 0.05) -> "jnp.ndarray":
     """Per-instance kept fraction restoring feasibility, closed form.
@@ -525,6 +527,7 @@ def ring_grid_coeffs(sc: GridScalars, ring_sizes, w1, w2, dtx, disl,
         t_fixed=bcast(t_fixed))
 
 
+@jax.named_scope("planner.coeffs")
 def ring_pass_coeffs(sc: GridScalars, n_sats, w1, w2, dtx, disl,
                      n_items, *, ring_n: Optional[int] = None
                      ) -> CoeffArrays:

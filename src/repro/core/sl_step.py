@@ -82,25 +82,36 @@ class SLStepResult:
 
 def _make_sl_grads(adapter: SplitAdapter, quantize_boundary: bool):
     """The traced body shared by make_sl_step and make_sl_pass:
-    (params_a, params_b, batch) -> (loss, g_a, g_b, payload_bits)."""
+    (params_a, params_b, batch) -> (loss, g_a, g_b, payload_bits).
+
+    Its ops carry the named scopes of the step's phases: ``sat_fwd``
+    (the satellite forward, ``jvp(sat_fwd)`` in an op's path),
+    ``ground`` (loss and backward of segment B) and ``sat_bwd`` (the
+    satellite VJP, ``sat_bwd/transpose(jvp(sat_fwd))``)."""
 
     q_bits = 8 if quantize_boundary else 32
 
+    @jax.named_scope("sat_fwd")
+    def sat_fwd(pa, batch):
+        return adapter.forward_a(pa, batch)
+
     def sl_grads(params_a, params_b, batch):
         # satellite forward, with vjp closure kept for step (7)
-        z, vjp_a = jax.vjp(lambda pa: adapter.forward_a(pa, batch), params_a)
+        z, vjp_a = jax.vjp(lambda pa: sat_fwd(pa, batch), params_a)
         z_tx = ops.ste_quantize(z) if quantize_boundary else z
 
         # ground: loss + backward wrt segment B and wrt the boundary
         def ground(pb, zz):
             return adapter.loss_b(pb, zz, batch)
 
-        loss, (g_b, g_z) = jax.value_and_grad(ground, argnums=(0, 1))(
-            params_b, z_tx)
+        with jax.named_scope("ground"):
+            loss, (g_b, g_z) = jax.value_and_grad(ground, argnums=(0, 1))(
+                params_b, z_tx)
 
         # uplink gradient (quantized the same way on the return path)
         g_z_tx = ops.ste_quantize(g_z) if quantize_boundary else g_z
-        (g_a,) = vjp_a(g_z_tx.astype(z.dtype))
+        with jax.named_scope("sat_bwd"):
+            (g_a,) = vjp_a(g_z_tx.astype(z.dtype))
 
         payload = z.size * q_bits
         return loss, g_a, g_b, payload
@@ -195,13 +206,16 @@ def make_pass_step(adapter: SplitAdapter, optimizer, *,
     pass) and by the device constellation engine
     (:mod:`repro.sim.device_sim`, where skip-below-reserve passes and
     beyond-allocation steps mask the same way) — so host and device
-    closed loops train through literally the same kernel.
+    closed loops train through literally the same kernel.  The optimizer
+    update runs under the named scope ``update``, beside the phases of
+    :func:`_make_sl_grads`.
     """
     sl_grads = _make_sl_grads(adapter, quantize_boundary)
 
     def pass_step(state, batch, valid):
         loss, g_a, g_b, _ = sl_grads(state.params_a, state.params_b, batch)
-        state = state.apply_updates(g_a, g_b, optimizer, where=valid)
+        with jax.named_scope("update"):
+            state = state.apply_updates(g_a, g_b, optimizer, where=valid)
         return state, jnp.where(valid, loss, jnp.nan)
 
     return pass_step

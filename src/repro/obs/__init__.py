@@ -9,7 +9,7 @@ a fresh fleet run.
 """
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       SyncBudgetExceeded, counter_property,
-                      global_registry, reset_global, sync_budget)
+                      global_registry, reset_global, sync_budget, to_host)
 from .ring import (EV_EXCHANGE, EV_PASS, EV_SERVE, EVENT_NAMES,
                    EXCHANGE_FIELDS, FIELDS_BY_KIND, PASS_FIELDS,
                    PAYLOAD_WIDTH, SERVE_FIELDS, FlightRecorder,
@@ -21,7 +21,7 @@ from .timeline import (timeline_summary, to_chrome_trace,
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "SyncBudgetExceeded", "counter_property", "global_registry",
-    "reset_global", "sync_budget",
+    "reset_global", "sync_budget", "to_host",
     "EV_EXCHANGE", "EV_PASS", "EV_SERVE", "EVENT_NAMES",
     "EXCHANGE_FIELDS", "FIELDS_BY_KIND", "PASS_FIELDS", "PAYLOAD_WIDTH",
     "SERVE_FIELDS", "FlightRecorder", "RingEvents", "TelemetryRing",

@@ -10,19 +10,34 @@ exposes the same counter names under its own namespace), *aggregable*
 *assertable* (:func:`sync_budget` turns the ≤-1-host-sync-per-revolution
 contract into a context manager any test can wrap around a run).
 
+Two instruments sit where the host meets the device:
+
+* :meth:`MetricsRegistry.span` — a host span that is both a
+  ``jax.profiler.TraceAnnotation`` (in the profiler's trace, on the
+  clock of the device events, when a profiler session is on; a no-op
+  otherwise) and a histogram of its durations, ``<name>_s``;
+* :func:`to_host` — the device→host copy of a pytree, array by array,
+  counted in ``d2h_arrays`` / ``d2h_bytes``.  ``host_syncs`` counts the
+  points where an engine waits for the device, not the copies.
+
 Compat: the engines keep their old attribute API via
 :func:`counter_property` — ``sim.host_syncs`` reads (and ``+= 1``
 writes) go straight through to the registry counter, so every existing
 test, benchmark and example keeps working unchanged.
 
 Everything here is host-side Python — nothing in this module is ever
-traced, and incrementing a counter never touches a device.
+traced, and incrementing a counter never touches a device (only
+:func:`to_host` copies from one).
 """
 from __future__ import annotations
 
 import contextlib
 import math
+import time
 from typing import Any, Dict, List, Optional
+
+import jax
+import numpy as np
 
 
 class Counter:
@@ -78,12 +93,13 @@ class Gauge:
 
 
 class Histogram:
-    """Streaming summary of a float series (dispatch latencies, window
+    """Streaming summary of a float series (span durations, window
     throughputs): count / sum / min / max plus power-of-two buckets.
 
     Buckets are ``le`` upper bounds in a fixed geometric ladder — good
     enough to eyeball a latency distribution in a BENCH JSON without
-    storing samples.
+    storing samples.  Samples propagate to the owning registry's parent
+    chain, like counter deltas.
     """
 
     kind = "histogram"
@@ -93,6 +109,7 @@ class Histogram:
 
     def __init__(self, name: str, registry: "MetricsRegistry"):
         self.name = name
+        self._registry = registry
         self.count = 0
         self.sum = 0.0
         self.min = math.inf
@@ -105,6 +122,7 @@ class Histogram:
         self.sum += x
         self.min = min(self.min, x)
         self.max = max(self.max, x)
+        self._registry._propagate_sample(self.name, x)
         for i, bound in enumerate(self.BOUNDS):
             if x <= bound:
                 self.buckets[i] += 1
@@ -131,6 +149,29 @@ class Histogram:
 _METRIC_TYPES = {"counter": Counter, "gauge": Gauge, "histogram": Histogram}
 
 
+class Span:
+    """One timed host region; see :meth:`MetricsRegistry.span`.
+
+    ``seconds`` holds the duration once the region has closed.
+    """
+
+    def __init__(self, registry: "MetricsRegistry", name: str, **stats):
+        self._hist = registry.histogram(f"{name}_s")
+        self._trace = jax.profiler.TraceAnnotation(registry._qualify(name),
+                                                   **stats)
+        self.seconds: Optional[float] = None
+
+    def __enter__(self) -> "Span":
+        self._trace.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._trace.__exit__(*exc)
+        self._hist.record(self.seconds)
+
+
 class MetricsRegistry:
     """A namespaced bag of metrics with get-or-create accessors.
 
@@ -139,12 +180,14 @@ class MetricsRegistry:
 
         self.metrics = MetricsRegistry("fleet", parent=global_registry())
         self.metrics.inc("traces")            # counter shorthand
-        self.metrics.histogram("dispatch_s").record(dt)
+        with self.metrics.span("launch"):     # histogram "launch_s"
+            ...
 
-    Counter deltas roll up the parent chain under the child's qualified
-    name (``fleet.traces``), so the global registry is always the sum
-    over every live engine — that aggregate is what lands in BENCH
-    JSONs and what :func:`sync_budget` guards by default.
+    Counter deltas and histogram samples roll up the parent chain under
+    the child's qualified name (``fleet.traces``), so the global
+    registry is always the sum over every live engine — that aggregate
+    is what lands in BENCH JSONs and what :func:`sync_budget` guards by
+    default.
     """
 
     def __init__(self, namespace: str = "",
@@ -176,6 +219,21 @@ class MetricsRegistry:
     def inc(self, name: str, n: int = 1) -> None:
         self.counter(name).inc(n)
 
+    def span(self, name: str, **stats) -> Span:
+        """A host span around a ``with`` block: a
+        ``jax.profiler.TraceAnnotation`` named ``<namespace>.<name>``
+        (``stats`` become the event's stats, e.g. an index shared by a
+        revolution's spans) and the block's duration in the histogram
+        ``<name>_s``.
+
+        The annotation is a no-op unless a profiler session is on; then
+        it lands in the same trace, on the same clock, as the device's
+        events, and spans nest as the blocks do.  Never open one inside
+        a traced function: it would time the trace, once, not the
+        device (``scripts/lint_scan_purity.py`` refuses it there).
+        """
+        return Span(self, name, **stats)
+
     # --------------------------------------------------- aggregation
     def _qualify(self, name: str) -> str:
         return f"{self.namespace}.{name}" if self.namespace else name
@@ -183,6 +241,10 @@ class MetricsRegistry:
     def _propagate(self, name: str, delta: int) -> None:
         if self.parent is not None and delta:
             self.parent.counter(self._qualify(name)).add(delta)
+
+    def _propagate_sample(self, name: str, x: float) -> None:
+        if self.parent is not None:
+            self.parent.histogram(self._qualify(name)).record(x)
 
     def counters_matching(self, suffix: str) -> List[Counter]:
         """Every counter whose name is ``suffix`` or ends with
@@ -216,6 +278,25 @@ def reset_global() -> MetricsRegistry:
     global _GLOBAL
     _GLOBAL = MetricsRegistry()
     return _GLOBAL
+
+
+# ----------------------------------------------------- device -> host
+
+def to_host(tree, metrics: Optional[MetricsRegistry] = None):
+    """Host copy of a pytree, ``jax.tree.map(np.asarray, tree)``: one
+    device→host copy per array, each counted in ``metrics``'
+    ``d2h_arrays`` and its bytes in ``d2h_bytes``.  A ``jax.Array``
+    whose host copy JAX already keeps (``np.asarray`` of it ran before,
+    and copied) is not copied again and not counted."""
+
+    leaves, treedef = jax.tree.flatten(tree)
+    copied = [x for x in leaves if isinstance(x, jax.Array)
+              and getattr(x, "_npy_value", None) is None]
+    host = [np.asarray(x) for x in leaves]
+    if metrics is not None and copied:
+        metrics.inc("d2h_arrays", len(copied))
+        metrics.inc("d2h_bytes", sum(x.nbytes for x in copied))
+    return jax.tree.unflatten(treedef, host)
 
 
 # --------------------------------------------------------- sync budget

@@ -8,10 +8,11 @@ tell the host has to either ride the scan outputs or break the
 first option made first-class: a fixed-size structured event buffer
 (kind / time / slot / float32 payload row) plus a monotonic cursor,
 carried through the scan like any other state and **flushed at the
-existing revolution-boundary sync** — the ring arrays come home inside
-the same host read as the dense telemetry, so recording events costs
-zero extra syncs (asserted via the metrics registry's ``host_syncs``
-counter, see :mod:`repro.obs.metrics`).
+existing revolution-boundary sync**: the device has finished the
+program by then, so the flush waits for nothing (the registry's
+``host_syncs`` counts no extra sync, see :mod:`repro.obs.metrics`).
+It is not free: each of the ring's five arrays is a device→host copy
+of its own, counted in ``d2h_arrays`` / ``d2h_bytes``.
 
 Device API (traceable, vmap-safe — the fleet engine records into a
 ``(P, ...)``-leading ring under its plane ``vmap``):
@@ -25,8 +26,9 @@ Device API (traceable, vmap-safe — the fleet engine records into a
 
 Host API:
 
-* :func:`flush` — one host copy of the ring, unwrapped into
-  chronological event arrays (+ the dropped-event count);
+* :func:`flush` — the ring copied to the host (one copy per array),
+  unwrapped into chronological event arrays (+ the dropped-event
+  count);
 * :class:`FlightRecorder` — accumulates flushed rings across
   dispatches/planes into one event table (feeding the engine's metrics
   registry), ready for :mod:`repro.obs.timeline` to render.
@@ -42,6 +44,8 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .metrics import to_host
 
 # ---------------------------------------------------------------- schema
 
@@ -148,12 +152,13 @@ class RingEvents(NamedTuple):
 
 
 def flush(ring: TelemetryRing) -> RingEvents:
-    """One device→host copy of a (flat) ring, unwrapped oldest-first.
+    """A (flat) ring copied to the host, unwrapped oldest-first.
 
-    Call it where the engine already syncs telemetry — the ring comes
-    home inside the same host read, so flushing adds no sync of its
-    own.  Rings with leading batch axes (one per plane) are flushed
-    per plane by :meth:`FlightRecorder.ingest`.
+    Call it where the engine already syncs telemetry: the device is
+    done with the ring by then, so flushing waits for no further device
+    work, but each of the ring's arrays is a device→host copy of its
+    own.  Rings with leading batch axes (one per plane) are flushed per
+    plane by :meth:`FlightRecorder.ingest`, which counts those copies.
     """
     host = TelemetryRing(*[np.asarray(a) for a in ring])
     if host.cursor.ndim != 0:
@@ -182,7 +187,8 @@ class FlightRecorder:
     they sync telemetry (one call per dispatch).  The recorder splits
     plane-batched rings, tags every event with its plane, feeds the
     engine's metrics registry (``events_recorded`` / ``events_dropped``
-    counters) and serves the merged, time-ordered event table to
+    counters, and ``d2h_arrays`` / ``d2h_bytes`` for the ring's copies)
+    and serves the merged, time-ordered event table to
     :mod:`repro.obs.timeline`.
     """
 
@@ -203,7 +209,7 @@ class FlightRecorder:
         sim engine's ``t`` restarts at 0 every dispatch; the fleet and
         serve engines record absolute indices and pass 0).
         """
-        host = TelemetryRing(*[np.asarray(a) for a in ring])
+        host = to_host(ring, self.metrics)
         planes = ([None] if host.cursor.ndim == 0
                   else range(host.cursor.shape[0]))
         n_total = 0

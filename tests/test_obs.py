@@ -352,14 +352,17 @@ def test_lint_scan_purity_flags_violations(tmp_path):
         "            jax.debug.print('k={}', x)\n"
         "            y = np.float32(c)\n"
         "            x.block_until_ready()\n"
+        "            with jax.profiler.TraceAnnotation('step'):\n"
+        "                c = c + 1\n"
         "            return c, y\n"
         "        return body\n")
     hits, found = lint.lint_file(str(bad), ("_compiled",))
     assert found == ["_compiled"]
     msgs = " ".join(m for _, _, m in hits)
-    assert len(hits) == 3
+    assert len(hits) == 4
     assert "jax.debug.print" in msgs
     assert "block_until_ready" in msgs and "numpy" in msgs
+    assert "jax.profiler.TraceAnnotation" in msgs
     # clean scope -> no hits; missing scope -> reported
     ok = tmp_path / "ok.py"
     ok.write_text("def _compiled():\n    return 1\n")
