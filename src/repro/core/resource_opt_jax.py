@@ -57,8 +57,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-# batch padding shares the repo-wide bucketing schedule with the pass
-# engine's step bucketing: O(log B) compilations, <=25% inert pad rows
+# batch padding shares the repo-wide bucketing schedule with
+# make_sl_pass(bucket=True): O(log B) compilations, <=25% inert pad rows
 from repro.utils.bucketing import bucket_size as _bucket_batch
 
 _EPS = 1e-12

@@ -96,6 +96,8 @@ class FleetConfig:
     battery_j: float = 5_000.0
     recharge_w: float = 20.0
     reserve_j: float = 100.0
+    # cap on the planned steps of a pass (None = uncapped); the static
+    # scan length is the plan's own largest step count
     max_steps_per_pass: Optional[int] = 128
     min_fraction: float = 0.05
     seed: int = 0

@@ -67,7 +67,6 @@ from repro.core.train_state import SLTrainState
 from repro.sim import energy_state as es_mod
 from repro.sim.energy_state import EnergyState, init_energy_state
 from repro.train.optimizer import resolve_optimizer
-from repro.utils.bucketing import bucket_size as _bucket_size
 from repro.utils.treeutil import tree_bytes
 
 ACTION_TRAINED = 0
@@ -196,10 +195,11 @@ def measure_and_plan(adapter: SplitAdapter, budget: PassBudget, batch_fn,
     handoff bytes from the live ``params_a``), plans the pass rows on
     device (or accepts an external ``plan``), and sizes the static
     per-pass scan from the plan's actual largest step count (ONE host
-    read, construction only), bucketed on the repo-wide schedule so
-    replans recompile O(log k) at most.  Returns
-    ``(batch_size, costs, plan, scan_steps)``.  Keeping this in one
-    place is what keeps the single-ring engine and the fleet engine
+    read, construction only; at least 1).  An engine plans once and
+    compiles its own programs, so the scan carries no padding steps
+    beyond that count: a pass with fewer planned steps masks the rest.
+    Returns ``(batch_size, costs, plan, scan_steps)``.  Keeping this in
+    one place is what keeps the single-ring engine and the fleet engine
     measuring and planning identically — the host-oracle parity
     invariant.
     """
@@ -220,7 +220,7 @@ def measure_and_plan(adapter: SplitAdapter, budget: PassBudget, batch_fn,
                                 max_steps_per_pass=max_steps_per_pass,
                                 min_fraction=min_fraction)
     k_max = int(np.asarray(jnp.max(plan.n_steps)))
-    return batch_size, costs, plan, _bucket_size(max(k_max, 1))
+    return batch_size, costs, plan, max(k_max, 1)
 
 
 class PassTelemetry(NamedTuple):
@@ -250,8 +250,9 @@ class DeviceSimConfig:
     battery_j: float = 5_000.0
     recharge_w: float = 20.0
     reserve_j: float = 100.0
-    # static scan length per pass; None = sized from the plan's largest
-    # step count (one host read at construction time)
+    # cap on the planned steps of a pass (None = uncapped); the static
+    # scan length is the plan's own largest step count (one host read
+    # at construction time)
     max_steps_per_pass: Optional[int] = 128
     min_fraction: float = 0.05
     seed: int = 0

@@ -1,10 +1,13 @@
 """Shared size-bucketing schedule for jit recompile control.
 
-One copy of the padding schedule used by both the fused pass engine
-(scan step counts, :mod:`repro.core.sl_step`) and the JAX solver
-backend (batch sizes, :mod:`repro.core.resource_opt_jax`): exact powers
-of two up to 16, then 1/8-octave granularity.  Keeping it in one place
-keeps the two engines' recompile-count guarantees in sync.
+One copy of the padding schedule used where a size arrives from the
+host on every call: the step count of ``make_sl_pass(bucket=True)``
+(:mod:`repro.core.sl_step`) and the JAX solver's batch padding
+(:mod:`repro.core.resource_opt_jax`): exact powers of two up to 16,
+then 1/8-octave granularity.  Keeping it in one place keeps their
+recompile-count guarantees in sync.  The device engines' pass scans
+do not use it: they plan once and size the scan at the plan's own
+largest step count (``repro.sim.device_sim.measure_and_plan``).
 """
 from __future__ import annotations
 

@@ -14,14 +14,16 @@ import pytest
 from repro.core.constellation import ConstellationConfig, ConstellationSim
 from repro.core.energy import PassBudget
 from repro.core.orbits import OrbitalPlane
-from repro.core.sl_step import autoencoder_adapter
+from repro.core.sl_step import autoencoder_adapter, make_sl_pass
 from repro.core.train_state import SLTrainState
 from repro.fleet import (FleetConfig, FleetEngine, average_planes,
                          build_event_schedule)
 from repro.sim.data import DeviceImageryShards
-from repro.sim.device_sim import (ACTION_NAMES, DeviceConstellationSim,
-                                  DeviceSimConfig, plan_ring_passes)
+from repro.sim.device_sim import (ACTION_NAMES, ACTION_TRAINED,
+                                  DeviceConstellationSim, DeviceSimConfig,
+                                  plan_ring_passes)
 from repro.train.optimizer import resolve_optimizer
+from repro.utils.bucketing import bucket_size
 
 SHARDS = DeviceImageryShards(img=32, batch=4)
 ADAPTER = autoencoder_adapter(cut=5, img=32)
@@ -291,7 +293,6 @@ def test_sweep_cell_feeds_fleet():
     """A planned sweep cell broadcasts into a (P, N) fleet plan the
     engine executes directly (mission -> fleet bridge)."""
     from repro.core.mission import sweep_revolutions
-    from repro.sim.device_sim import ACTION_TRAINED
 
     budget = _budget()
     cfg = FleetConfig(n_planes=2, n_revolutions=1, max_steps_per_pass=8,
@@ -308,6 +309,77 @@ def test_sweep_cell_feeds_fleet():
     res = fleet2.run()
     assert (res.action == ACTION_TRAINED).all()
     assert np.isfinite(res.loss).all()
+
+
+# ------------------------------------------------------ pass scan sizing
+
+@pytest.mark.parametrize("k", [0, 3, 5, 12])
+def test_scan_steps_are_the_plans_largest_count(k):
+    """Both device engines size the per-pass scan at the plan's own
+    largest step count (at least 1), not at a bucketed one: an engine
+    plans once, so padding steps would only compute masked no-ops."""
+    budget = _budget()
+    base = plan_ring_passes(budget, ADAPTER.costs(), batch_size=4,
+                            n_sats=4, max_steps_per_pass=8)
+    row = np.array([k // 2, k, 0, k], np.int32)
+    ring = DeviceConstellationSim(
+        ADAPTER, budget, SHARDS, DeviceSimConfig(seed=0),
+        plan=base._replace(n_steps=jnp.asarray(row)))
+    fleet_plan = jax.tree.map(lambda x: jnp.stack([x, x]), base)
+    fleet = FleetEngine(
+        ADAPTER, budget, SHARDS, FleetConfig(n_planes=2, seed=0),
+        plan=fleet_plan._replace(n_steps=jnp.asarray(np.stack([row, row]))))
+    assert ring._scan_steps == fleet._scan_steps == max(k, 1)
+
+
+@pytest.mark.parametrize("n_items,k,optimizer",
+                         [(12.0, 3, "sgd"), (20.0, 5, "adamw")])
+def test_exact_scan_matches_padded_pass_chain(n_items, k, optimizer):
+    """One revolution of a fault-free plane whose plan gives k steps a
+    pass (not a power of two): the padding steps the scan no longer runs
+    were exact no-ops.  The final state equals, bit for bit, the same
+    fleet run at the bucketed scan length, and matches chaining the
+    padded ``make_sl_pass(bucket=True)`` pass by pass over the same
+    batches (a separately compiled program: equal up to rounding)."""
+    assert bucket_size(k) > k
+    budget = _budget(n_sats=3, n_items=n_items)
+    cfg = FleetConfig(n_planes=1, n_revolutions=1, optimizer=optimizer,
+                      avg_every=0, seed=0)
+    fleet = FleetEngine(ADAPTER, budget, SHARDS, cfg)
+    assert fleet._scan_steps == k
+    plan_steps = np.asarray(fleet.plan.n_steps)[0]
+    assert (plan_steps == k).all()
+    res = fleet.run()
+    assert (res.action == ACTION_TRAINED).all()
+    np.testing.assert_array_equal(res.n_steps[0], plan_steps[res.sat[0]])
+
+    padded = FleetEngine(ADAPTER, budget, SHARDS, cfg)
+    padded._scan_steps = bucket_size(k)
+    res_padded = padded.run()
+    np.testing.assert_array_equal(res.loss, res_padded.loss)
+    for got, want in zip(jax.tree.leaves(res.state),
+                         jax.tree.leaves(res_padded.state)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    opt = resolve_optimizer(cfg.optimizer, lr=cfg.lr)
+    state = SLTrainState.create(*ADAPTER.init(jax.random.key(cfg.seed)),
+                                opt)
+    sl_pass = make_sl_pass(ADAPTER, optimizer=opt, bucket=True,
+                           donate=False)
+    bidx = 0
+    for slot in res.sat[0]:
+        n = int(plan_steps[slot])
+        state = sl_pass(state, [SHARDS(int(slot), bidx + j)
+                                for j in range(n)]).state
+        bidx += n
+    assert int(np.asarray(fleet._batch_idx)[0]) == bidx
+    assert int(np.asarray(res.state.step)[0]) == int(state.step) \
+        == plan_steps.sum()
+    for got, want in zip(jax.tree.leaves(res.state), jax.tree.leaves(state)):
+        got, want = np.asarray(got)[0], np.asarray(want)
+        scale = float(np.abs(want).max()) if want.size else 0.0
+        np.testing.assert_allclose(got, want, rtol=2e-4,
+                                   atol=2e-4 * scale)
 
 
 def test_fleet_chaining_and_counters():
